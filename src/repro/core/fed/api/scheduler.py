@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.fed import faults as ffaults
 from repro.core.fed.api import phases
 from repro.core.fed.cohort import latency as flatency
@@ -103,8 +104,10 @@ class SyncScheduler(Scheduler):
     def step(self, session) -> Dict[str, Any]:
         if self.robust:
             return self._robust_step(session)
-        session.state, metrics = self.substrate.run_round(
-            session.state, session.round_key(session.round), session.round)
+        key = session.round_key(session.round)
+        with obs.span("qfed.round"):
+            session.state, metrics = self.substrate.run_round(
+                session.state, key, session.round)
         session.round += 1
         return metrics
 

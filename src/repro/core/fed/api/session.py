@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt
+from repro import obs
 from repro.core.fed.api.scheduler import Scheduler, make_scheduler
 from repro.core.fed.api.spec import FedSpec
 from repro.core.fed.api.substrate import Substrate, make_substrate
@@ -203,14 +204,15 @@ class FederationSession:
         the legacy ``fed.train``; with ``rounds`` given, the legacy
         pre-split round-key plan ``split(k_loop, rounds)`` is installed
         so histories match the old loop bit-for-bit."""
-        substrate = substrate if substrate is not None else \
-            make_substrate(spec)
-        k_init, k_loop = jax.random.split(jnp.asarray(key))
-        state = substrate.init_state(k_init, params=params)
-        if rounds is not None and round_keys is None:
-            round_keys = jax.random.split(k_loop, rounds)
-        return cls(spec, substrate, key=k_loop, state=state,
-                   round_keys=round_keys)
+        with obs.span("qfed.create"):
+            substrate = substrate if substrate is not None else \
+                make_substrate(spec)
+            k_init, k_loop = jax.random.split(jnp.asarray(key))
+            state = substrate.init_state(k_init, params=params)
+            if rounds is not None and round_keys is None:
+                round_keys = jax.random.split(k_loop, rounds)
+            return cls(spec, substrate, key=k_loop, state=state,
+                       round_keys=round_keys)
 
     @classmethod
     def resume(cls, path: str, substrate: Optional[Substrate] = None
@@ -281,14 +283,16 @@ class FederationSession:
     # -- driving --------------------------------------------------------
     def round_key(self, t: int) -> jax.Array:
         """Round ``t``'s RNG key — pure in (checkpointed RNG state, t)."""
-        if self.round_keys is not None and t < self.round_keys.shape[0]:
-            return self.round_keys[t]
-        return jax.random.fold_in(self.key, t)
+        with obs.span("qfed.round_key"):
+            if self.round_keys is not None and t < self.round_keys.shape[0]:
+                return self.round_keys[t]
+            return jax.random.fold_in(self.key, t)
 
     def step(self) -> Dict[str, Any]:
         """One federation round — one server COMMIT under the spec's
         scheduler; returns the round metrics."""
-        return self.scheduler.step(self)
+        with obs.span("qfed.step"):
+            return self.scheduler.step(self)
 
     @property
     def sim_clock(self) -> Optional[float]:
@@ -332,7 +336,8 @@ class FederationSession:
     # -- evaluation / history -------------------------------------------
     def evaluate(self) -> Dict[str, float]:
         """Substrate metrics for the CURRENT state (one host sync)."""
-        return self.substrate.evaluate(self.state)
+        with obs.span("qfed.eval"):
+            return self.substrate.evaluate(self.state)
 
     def record_eval(self, verbose: bool = False) -> Dict[str, float]:
         """Evaluate and append to ``history`` under ``iteration`` =

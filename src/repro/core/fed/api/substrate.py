@@ -35,6 +35,7 @@ from typing import Any, Dict, Optional, Protocol, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.fed import channel as fchannel
 from repro.core.fed import participation, server_opt as fserver_opt
 from repro.core.fed import fed_step
@@ -66,7 +67,8 @@ class Substrate(Protocol):
 
 def _device_get_floats(tree) -> Dict[str, float]:
     """One host transfer for a (possibly nested) dict of scalars."""
-    host = jax.device_get(tree)
+    with obs.span("qfed.fetch"):
+        host = jax.device_get(tree)
     flat = {}
 
     def walk(prefix, t):
@@ -99,6 +101,10 @@ class QuantumSubstrate:
 
     def __init__(self, spec: FedSpec, dataset=None,
                  test: Optional[Tuple[jax.Array, jax.Array]] = None):
+        with obs.span("qfed.create"):
+            self._build(spec, dataset, test)
+
+    def _build(self, spec, dataset, test):
         from repro.core.quantum import data as qdata
 
         if spec.substrate != "quantum":

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.fed.api.session import FederationSession
 from repro.core.fed.api.spec import FedSpec
 from repro.core.fed.serve.admission import SlotGrid
@@ -291,7 +292,8 @@ class StackedGroup:
         # data, numerical blow-up) is flagged for the server to
         # quarantine — the vmapped tick already kept it from touching
         # any other slot's buffers
-        fin = np.asarray(jax.device_get(_slot_finite(self._params)))
+        with obs.span("qfed.fetch"):
+            fin = np.asarray(jax.device_get(_slot_finite(self._params)))
         for slot in np.nonzero(active & ~fin)[0]:
             self._faulted.append(
                 (int(slot), "non-finite model state after stacked tick"))

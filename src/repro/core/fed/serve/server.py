@@ -33,6 +33,7 @@ from typing import Dict, Optional
 
 import jax
 
+from repro import obs
 from repro.core.fed.api.session import FederationSession
 from repro.core.fed.api.spec import FedSpec
 from repro.core.fed.serve.groups import group_key, group_mode, make_group
@@ -81,6 +82,9 @@ class FederationServer:
         # fault (non-finite state, deadline/retry exhaustion); their
         # last good-or-bad state is parked for inspection
         self.quarantined: Dict[str, str] = {}
+        # sid -> submission time, kept while spans are recorded, for
+        # the ``qfed.queue`` interval (submit to seat)
+        self._queued_at: Dict[str, float] = {}
         self._seq = 0
         self.ticks = 0
 
@@ -94,6 +98,10 @@ class FederationServer:
         so a replayed submission sequence is deterministic) to have the
         server create the session, or a prebuilt ``session``. ``rounds``
         is the budget ON TOP of the session's current round."""
+        with obs.span("qfed.submit"):
+            return self._submit(spec, key, rounds, session, sid)
+
+    def _submit(self, spec, key, rounds, session, sid) -> str:
         if (spec is None) == (session is None):
             raise ValueError("pass exactly one of spec= or session=")
         if rounds < 0:
@@ -122,6 +130,8 @@ class FederationServer:
             self.done.add(sid)
         else:
             group.grid.submit(sid)
+            if obs.ON:
+                self._queued_at[sid] = obs.clock()
         return sid
 
     # -- the serving loop ------------------------------------------------
@@ -130,37 +140,49 @@ class FederationServer:
         up to ``rounds_per_tick`` rounds per occupied slot (one STACKED
         dispatch per stacked group), retire spent tenants. Returns tick
         stats."""
+        with obs.span("qfed.tick"):
+            return self._tick()
+
+    def _tick(self) -> Dict[str, int]:
         admitted = stepped = retired = quarantined = 0
         for group in self.groups.values():
-            claims = []
-            for slot, sid in group.grid.admit():
-                session = self.store.get(sid)   # revives if parked
-                self.store.pin(sid)             # truth moves on-device
-                claims.append((slot, session, self._target[sid]))
-            group.seat_many(claims)             # one scatter per wave
+            with obs.span("qfed.admit"):
+                claims = []
+                for slot, sid in group.grid.admit():
+                    session = self.store.get(sid)   # revives if parked
+                    self.store.pin(sid)             # truth moves on-device
+                    claims.append((slot, session, self._target[sid]))
+                    t0 = self._queued_at.pop(sid, None)
+                    if t0 is not None:
+                        obs.interval("qfed.queue", t0, obs.clock(), sid)
+            with obs.span("qfed.seat"):
+                group.seat_many(claims)             # one scatter per wave
             admitted += len(claims)
-            stepped += group.step()
-            # failure isolation: a faulted tenant is pulled off the grid
-            # BEFORE retirement so its slot frees for the next in line;
-            # its state (possibly poisoned) parks to disk for inspection
-            # and the diagnostic lands in ``quarantined``
-            for slot, diag in group.take_faulted():
-                sid = group.grid.sid[slot]
-                if sid is None:
-                    continue
-                group.unseat(slot)
-                self.store.unpin(sid)
-                self.store.park(sid)
-                self.quarantined[sid] = diag
-                quarantined += 1
-            for slot, sid in enumerate(group.grid.sid):
-                if sid is None:
-                    continue
-                if group.round_of(slot) >= self._target[sid]:
-                    group.unseat(slot)          # syncs state + frees slot
+            with obs.span("qfed.dispatch"):
+                stepped += group.step()
+            with obs.span("qfed.retire"):
+                # failure isolation: a faulted tenant is pulled off the
+                # grid BEFORE retirement so its slot frees for the next
+                # in line; its state (possibly poisoned) parks to disk
+                # for inspection and the diagnostic lands in
+                # ``quarantined``
+                for slot, diag in group.take_faulted():
+                    sid = group.grid.sid[slot]
+                    if sid is None:
+                        continue
+                    group.unseat(slot)
                     self.store.unpin(sid)
-                    self.done.add(sid)
-                    retired += 1
+                    self.store.park(sid)
+                    self.quarantined[sid] = diag
+                    quarantined += 1
+                for slot, sid in enumerate(group.grid.sid):
+                    if sid is None:
+                        continue
+                    if group.round_of(slot) >= self._target[sid]:
+                        group.unseat(slot)      # syncs state + frees slot
+                        self.store.unpin(sid)
+                        self.done.add(sid)
+                        retired += 1
         self.ticks += 1
         return {"admitted": admitted, "stepped": stepped,
                 "retired": retired, "quarantined": quarantined,

@@ -22,6 +22,7 @@ import re
 from collections import OrderedDict
 from typing import Dict, Iterable, Optional, Set
 
+from repro import obs
 from repro.core.fed.api.session import FederationSession
 
 _SID_RE = re.compile(r"^[\w.-]+$")
@@ -39,7 +40,8 @@ class CheckpointStore:
 
     capacity=None (default) never auto-parks — ``park`` stays explicit;
     with a capacity, adding or reviving past it parks the
-    least-recently-used UNPINNED session first.
+    least-recently-used UNPINNED session first. Parks and revives are
+    counted as ``qfed.park`` / ``qfed.revive`` (``repro.obs``).
     """
 
     def __init__(self, root: str, capacity: Optional[int] = None):
@@ -51,8 +53,6 @@ class CheckpointStore:
         self._live: "OrderedDict[str, FederationSession]" = OrderedDict()
         self._parked: Dict[str, str] = {}       # sid -> checkpoint path
         self._pinned: Set[str] = set()
-        self.parks = 0                          # eviction counters
-        self.revives = 0
 
     def path(self, sid: str) -> str:
         return os.path.join(self.root, f"{_check_sid(sid)}.npz")
@@ -88,7 +88,7 @@ class CheckpointStore:
             return self._live[sid]
         if sid in self._parked:
             session = FederationSession.resume(self._parked.pop(sid))
-            self.revives += 1
+            obs.count("qfed.revive")
             self._live[sid] = session
             self._evict_over()
             return session
@@ -125,7 +125,7 @@ class CheckpointStore:
         path = self.path(sid)
         session.save(path)
         self._parked[sid] = path
-        self.parks += 1
+        obs.count("qfed.park")
         return path
 
     def _evict_over(self) -> None:
