@@ -156,7 +156,7 @@ def phase_wide(rounds: int = 3, expect_kernels: bool = True,
             sub = sess.substrate
             lowered = {
                 "round": fed.lower_server_round(
-                    params[name], sub.dataset, sess.round_key(0), sub.cfg),
+                    params[name], sub.dataset, sess.key, sub.cfg),
                 "eval": fed.evaluate.lower(
                     params[name], test_in, test_out, spec.widths,
                     impl="pallas")}

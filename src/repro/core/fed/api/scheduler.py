@@ -76,7 +76,12 @@ class Scheduler:
 
 class SyncScheduler(Scheduler):
     """Lock-step Alg. 2 — bit-compatible with the pre-scheduler session:
-    one fused ``run_round`` per step, keyed by the round index.
+    one fused round per step, keyed by the round index. A round the
+    session's key plan does not cover goes to ``run_round_from(state,
+    base_key, t)``, which on the quantum substrate folds ``t`` into the
+    key inside the round program (one launch, the key still
+    ``fold_in(base_key, t)``); a planned round runs ``run_round`` under
+    ``session.round_key(t)``.
 
     With fault injection (``FedSpec.fault_model``) or a round deadline
     (``FedSpec.round_deadline``) active, the step runs the PHASED round
@@ -104,10 +109,16 @@ class SyncScheduler(Scheduler):
     def step(self, session) -> Dict[str, Any]:
         if self.robust:
             return self._robust_step(session)
-        key = session.round_key(session.round)
-        with obs.span("qfed.round"):
-            session.state, metrics = self.substrate.run_round(
-                session.state, key, session.round)
+        t = session.round
+        if session.planned(t):
+            key = session.round_key(t)
+            with obs.span("qfed.round"):
+                session.state, metrics = self.substrate.run_round(
+                    session.state, key, t)
+        else:
+            with obs.span("qfed.round"):
+                session.state, metrics = self.substrate.run_round_from(
+                    session.state, session.key, np.int32(t))
         session.round += 1
         return metrics
 

@@ -20,7 +20,11 @@ session's checkpointed RNG state and ``t`` — by default
 (an (n, 2) uint32 stack) overrides it for rounds it covers, which is
 how the legacy ``fed.train`` / ``launch/fed_train.py`` key schedules
 are reproduced exactly (see ``sequential_split_plan``). Purity in
-``t`` is what makes kill-and-resume exact.
+``t`` is what makes kill-and-resume exact. Where the fold happens is
+not part of the contract: the sync scheduler hands a round no plan
+covers ``(base_key, t)`` and the quantum round program folds ``t`` in
+itself (``Substrate.run_round_from``, one launch a round); every other
+path derives the key eagerly through ``round_key``.
 """
 from __future__ import annotations
 
@@ -281,10 +285,14 @@ class FederationSession:
         return tree
 
     # -- driving --------------------------------------------------------
+    def planned(self, t: int) -> bool:
+        """True when the round-key plan covers round ``t``."""
+        return self.round_keys is not None and t < self.round_keys.shape[0]
+
     def round_key(self, t: int) -> jax.Array:
         """Round ``t``'s RNG key — pure in (checkpointed RNG state, t)."""
         with obs.span("qfed.round_key"):
-            if self.round_keys is not None and t < self.round_keys.shape[0]:
+            if self.planned(t):
                 return self.round_keys[t]
             return jax.random.fold_in(self.key, t)
 

@@ -10,6 +10,10 @@ never branches on which physics it is driving:
   iteration (Alg. 1 + Alg. 2): the CANONICAL composition of the four
   round phases (``repro.core.fed.api.phases``), fused where the
   substrate can; returns ``(new_state, metrics)``.
+* ``run_round_from(state, base_key, t)`` — ``run_round`` under the
+  session's round key ``fold_in(base_key, t)``. The protocol's default
+  folds eagerly; ``QuantumSubstrate`` folds inside its round program,
+  so a sync round is one device launch.
 * the four phases themselves — ``select`` / ``local_update`` /
   ``transmit`` / ``aggregate`` (+ ``split_round_key`` and
   ``upload_restore``) — for schedulers that interleave phases of
@@ -55,6 +59,12 @@ class Substrate(Protocol):
                   ) -> Tuple[Any, Dict[str, Any]]:
         ...
 
+    def run_round_from(self, state: Any, base_key: jax.Array, t
+                       ) -> Tuple[Any, Dict[str, Any]]:
+        with obs.span("qfed.round_key"):
+            key = jax.random.fold_in(base_key, t)
+        return self.run_round(state, key, int(t))
+
     def evaluate(self, state: Any) -> Dict[str, float]:
         ...
 
@@ -82,7 +92,7 @@ def _device_get_floats(tree) -> Dict[str, float]:
     return flat
 
 
-class QuantumSubstrate:
+class QuantumSubstrate(Substrate):
     """QuanFedPS on the dissipative-QNN simulator (Alg. 1/2 proper).
 
     State is the QNN params: a list of per-layer stacked complex
@@ -173,10 +183,21 @@ class QuantumSubstrate:
     def run_round(self, state, key, round):
         from repro.core.quantum import federated as fed
         del round  # the quantum round is pure in (state, key)
-        params, smom, bound = fed.server_round_certified(
+        return self._round_out(state, *fed.server_round_certified(
             self._params_of(state), self.dataset, key, self.cfg,
             smom=self._smom_of(state), server_opt=self.spec.server_opt,
-            server_beta=self.spec.server_momentum, probe=self._probe)
+            server_beta=self.spec.server_momentum, probe=self._probe))
+
+    def run_round_from(self, state, base_key, t):
+        # one launch: the round program folds t into the key itself
+        from repro.core.quantum import federated as fed
+        obs.count("qfed.round_key.in_program")
+        return self._round_out(state, *fed.server_round_from(
+            self._params_of(state), self.dataset, base_key, t, self.cfg,
+            smom=self._smom_of(state), server_opt=self.spec.server_opt,
+            server_beta=self.spec.server_momentum, probe=self._probe))
+
+    def _round_out(self, state, params, smom, bound):
         if not self._certified:
             return self._pack(params, smom), {}
         err = self._err_of(state) + bound
@@ -298,7 +319,7 @@ class QuantumSubstrate:
         return self._pack(params, smom, err_bound)
 
 
-class ClassicalSubstrate:
+class ClassicalSubstrate(Substrate):
     """QuanFedPS's classical limit: I_l local optimizer steps per node +
     weighted delta aggregation (``fed_train_round``) on a pytree model.
 

@@ -10,9 +10,11 @@ drives every occupied slot's next round as ONE
 per-slot state lives RESIDENT in stacked device buffers (admission
 scatters a session in, retirement gathers it out — the grid is never
 re-stacked per tick), per-slot round keys are ``fold_in(base_key,
-round)`` exactly like ``FederationSession.round_key``, and idle slots
-compute but their results are masked out (the fixed-shape price of
-continuous batching, same as the decode scheduler's frozen caches).
+round)`` under the session RNG contract (``repro.core.fed.api.
+session``), folded in the program as a sync session's own round does,
+and idle slots compute but their results are masked out (the
+fixed-shape price of continuous batching, same as the decode
+scheduler's frozen caches).
 
 Sessions the stacked path cannot drive — classical substrates (their
 round pulls host-side data pools), async/overlapped schedules (their
@@ -116,9 +118,10 @@ def _serve_tick(params, smom, err, data, base_keys, rounds, active,
                 targets, eta, eps, beta, probe, cfg, server_opt, k):
     """One WHOLE serving tick as a single dispatch: a ``lax.scan`` of
     ``k`` federation rounds, each with per-slot round keys
-    (``fold_in(base, t)`` — the exact ``FederationSession.round_key``
-    contract, so a session sees the same key stream stacked as it would
-    stepping alone) and a live-mask merge that freezes idle slots AND
+    (``fold_in(base, t)`` — the session RNG contract, which a sync
+    session's round folds in its program too, so a session sees the
+    same key stream stacked as it would stepping alone) and a live-mask
+    merge that freezes idle slots AND
     slots whose round budget ran out mid-scan: a slot advances exactly
     ``min(k, target - round)`` rounds, then coasts with its updates
     discarded (the fixed-shape price of batching, like the decode

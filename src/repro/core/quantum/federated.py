@@ -63,6 +63,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.fed import channel as fchannel
@@ -576,6 +577,18 @@ _server_round = functools.partial(
         _server_round_impl)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "server_opt"))
+def _server_round_from(params, smom, dataset, base_key, t, eta, eps,
+                       server_beta, cfg, mesh=None, server_opt="none",
+                       probe=None):
+    """``_server_round`` under the session's round key ``fold_in(base_key,
+    t)``, folded inside the same program: a session's round in one
+    launch."""
+    return _server_round_impl(params, smom, dataset,
+                              jax.random.fold_in(base_key, t), eta, eps,
+                              server_beta, cfg, mesh, server_opt, probe)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "server_opt"))
 def _server_round_stacked(params, smom, dataset, keys, eta, eps,
                           server_beta, probe, cfg, server_opt):
@@ -627,7 +640,7 @@ def server_round_stacked(params: qnn.Params, dataset: QuantumDataset,
 
 def _resolve_fanout(cfg: QuantumFedConfig) -> str:
     """Pick the fan-out OUTSIDE jit. The resolved mode travels in the
-    static cfg and the mesh itself is a static arg of ``_server_round``
+    static cfg and the mesh itself is a static arg of the round programs
     (Mesh is hashable), so a round traced mesh-less is never replayed
     for a mesh run, nor one mesh's shard_map trace for another mesh."""
     if cfg.fanout == "vmap":
@@ -693,6 +706,23 @@ def server_round_certified(params: qnn.Params, dataset: QuantumDataset,
     static_cfg, mesh = _round_statics(cfg)
     return _server_round(params, smom, dataset, key, cfg.eta, cfg.eps,
                          server_beta, static_cfg, mesh, server_opt, probe)
+
+
+def server_round_from(params: qnn.Params, dataset: QuantumDataset,
+                      base_key: jax.Array, t, cfg: QuantumFedConfig,
+                      smom=None, server_opt: str = "none",
+                      server_beta: float = 0.9, probe=None):
+    """``server_round_certified`` keyed by ``fold_in(base_key, t)`` —
+    the session's RNG contract — with the fold inside the round program:
+    no eager key derivation, and no launch of its own, before the round.
+    Pass ``t`` as an ``np.int32`` so that every round reuses one compiled
+    program. Bit-identical to ``server_round_certified(params, dataset,
+    jax.random.fold_in(base_key, t), cfg, ...)``."""
+    fserver_opt.validate(server_opt)
+    static_cfg, mesh = _round_statics(cfg)
+    return _server_round_from(params, smom, dataset, base_key, t, cfg.eta,
+                              cfg.eps, server_beta, static_cfg, mesh,
+                              server_opt, probe)
 
 
 # Per-phase entry points: same bodies as the fused round, each under its
@@ -771,9 +801,9 @@ def aggregate_phase(params: qnn.Params, ks_all: List[jax.Array],
 
 
 def _round_statics(cfg: QuantumFedConfig):
-    """The static (cfg, mesh) pair `_server_round` is traced under —
+    """The static (cfg, mesh) pair the round programs are traced under —
     eta/eps zeroed out of the cache key, fanout resolved against the
-    ambient mesh. Shared by ``server_round`` and ``lower_server_round``
+    ambient mesh. Shared by the round entries and ``lower_server_round``
     so dryruns lower exactly the trace training executes."""
     strategies.get_aggregation(cfg.aggregation)   # fail loudly pre-trace
     participation.validate(cfg.participation)
@@ -785,10 +815,13 @@ def _round_statics(cfg: QuantumFedConfig):
 def lower_server_round(params: qnn.Params, dataset: QuantumDataset,
                        key: jax.Array, cfg: QuantumFedConfig):
     """Lower (not run) one round under the ambient mesh — the dryrun /
-    benchmark hook, using the same static-cfg protocol as training."""
+    benchmark hook. It lowers the program a session's sync round runs
+    (``server_round_from``: base ``key`` and an int32 round index in,
+    the fold inside), under the same static-cfg protocol as training."""
     static_cfg, mesh = _round_statics(cfg)
-    return _server_round.lower(params, None, dataset, key, cfg.eta,
-                               cfg.eps, 0.0, static_cfg, mesh, "none")
+    return _server_round_from.lower(params, None, dataset, key,
+                                    np.int32(0), cfg.eta, cfg.eps, 0.0,
+                                    static_cfg, mesh, "none")
 
 
 @functools.partial(jax.jit, static_argnames=("widths", "impl"))

@@ -137,6 +137,46 @@ def test_sync_scheduler_matches_frozen_session_loop():
         assert_states_equal(sess.state, state)
 
 
+BIT_SPECS = {
+    "default": {},
+    "momentum": dict(aggregation="average", server_opt="momentum",
+                     server_momentum=0.5),
+    "certified": dict(rank_cap=2),
+}
+
+
+@pytest.mark.parametrize("case", ["default", "momentum", "certified",
+                                  "resumed", "planned"])
+def test_sync_round_key_folded_in_program_is_bit_identical(case,
+                                                           tmp_path):
+    """A sync session's rounds (the key folded inside the round program
+    where no plan covers t) equal the eager loop ``state <-
+    run_round(state, fold_in(key, t), t)`` bit for bit; resumed mid-run
+    (t starts at 2), and with a plan installed (the eager path)."""
+    spec = qspec(**BIT_SPECS.get(case, {}))
+    sub = api.make_substrate(spec)
+    plan = (jax.random.split(jax.random.PRNGKey(3), 4)
+            if case == "planned" else None)
+    sess = api.FederationSession.create(spec, jax.random.PRNGKey(7),
+                                        substrate=sub, round_keys=plan)
+    state = sess.state
+    for t in range(4):
+        key = (plan[t] if plan is not None
+               else jax.random.fold_in(sess.key, t))
+        state, _ = sub.run_round(state, key, t)
+    if case == "resumed":
+        sess.run(2)
+        path = str(tmp_path / "ckpt")
+        sess.save(path)
+        sess = api.FederationSession.resume(path, substrate=sub)
+        assert sess.round == 2
+        sess.run(2)
+    else:
+        sess.run(4)
+    assert sess.round == 4
+    assert_states_equal(sess.state, state)
+
+
 # ------------------------------------------------------- async scheduling
 
 def test_async_deterministic_and_distinct_from_sync():

@@ -1,4 +1,5 @@
 """``repro.obs``: spans and counters inside the federation program."""
+import dataclasses
 import time
 
 import jax
@@ -114,16 +115,44 @@ def test_session_step_and_eval_spans(recording):
     # the session, and the substrate it builds
     assert agg["qfed.create"]["n"] == 1
     assert agg["qfed.create/qfed.create"]["n"] == 1
-    assert agg["qfed.step/qfed.round_key"]["n"] == 2
+    # no plan: the round program folds the key in, no eager round_key
+    assert "qfed.step/qfed.round_key" not in agg
+    assert snap["counters"]["qfed.round_key.in_program"] == 2
     assert agg["qfed.eval/qfed.fetch"]["n"] == 2
-    assert _children(snap, "qfed.step") == [["qfed.round",
-                                             "qfed.round_key"]] * 2
+    assert _children(snap, "qfed.step") == [["qfed.round"]] * 2
     # EvalEvery records at round 0 and round 2
     assert _children(snap, "qfed.eval") == [["qfed.fetch"]] * 2
     by_id, _ = _tree(snap)
     for s in snap["spans"]:
         if s["name"] == "qfed.create" and s["parent"] is not None:
             assert by_id[s["parent"]]["name"] == "qfed.create"
+
+
+def test_sync_rounds_fold_the_key_in_program(recording):
+    sess = FederationSession.create(SPEC, jax.random.PRNGKey(0))
+    sess.step()  # compiles
+    before = obs.snapshot()["counters"]["qfed.round_key.in_program"]
+    sess.run(3)
+    snap = obs.snapshot()
+    assert snap["counters"]["qfed.round_key.in_program"] - before == 3
+    assert snap["aggregates"]["qfed.step"]["n"] == 4
+    assert not [s for s in snap["spans"] if s["name"] == "qfed.round_key"]
+
+
+@pytest.mark.parametrize("how", ["planned", "async"])
+def test_eager_round_keys_keep_their_span(recording, how):
+    if how == "planned":
+        sess = FederationSession.create(SPEC, jax.random.PRNGKey(0),
+                                        rounds=3)
+    else:
+        sess = FederationSession.create(
+            dataclasses.replace(SPEC, schedule="async", async_commit=2),
+            jax.random.PRNGKey(0))
+    sess.run(3)
+    snap = obs.snapshot()
+    assert "qfed.round_key.in_program" not in snap["counters"]
+    keys = [s for s in snap["spans"] if s["name"] == "qfed.round_key"]
+    assert len(keys) == 3
 
 
 def test_server_tick_spans_and_queue_intervals(recording, tmp_path):

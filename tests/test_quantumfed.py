@@ -185,3 +185,24 @@ def test_channel_noise_unitary_and_robust():
                          hist["test_fidelity"][-1])
     assert results[2.0][1] > results[2.0][0] + 0.03   # still learns
     assert results[100.0][1] < results[2.0][1]        # noise floor hurts
+
+
+def test_lower_server_round_is_the_session_round():
+    """``lower_server_round`` lowers the program a sync session's round
+    runs: base key and round index in, the threefry fold inside, and
+    the compiled result equal to the round under ``fold_in(key, t)``."""
+    _, ds, _ = small_setup(jax.random.PRNGKey(0))
+    params = qnn.init_params(jax.random.PRNGKey(1), WIDTHS)
+    cfg = fed.QuantumFedConfig(widths=WIDTHS, num_nodes=4,
+                               nodes_per_round=2, interval_length=2)
+    key = jax.random.PRNGKey(2)
+    lowered = fed.lower_server_round(params, ds, key, cfg)
+    args, _ = lowered.args_info
+    assert args[3].shape == (2,) and args[4].dtype == jnp.int32
+    assert args[4].shape == ()
+    assert "threefry" in lowered.as_text()
+    out, _, _ = lowered.compile()(params, None, ds, key, np.int32(3),
+                                  cfg.eta, cfg.eps, 0.0)
+    ref = fed.server_round(params, ds, jax.random.fold_in(key, 3), cfg)
+    for a, b in zip(out, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
